@@ -28,7 +28,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.distributed.ring import _present_axes, _rotate
 from repro.gp.hyperparams import HyperParams
@@ -150,10 +149,10 @@ def distributed_ap_sweeps(
         return v_loc, r
 
     spec = P(axes, None)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec, spec, spec),
         out_specs=(spec, spec),
-        check_rep=False,
+        check_vma=False,
     )(x, b_rhs, v0)

@@ -17,7 +17,6 @@ from __future__ import annotations
 import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 from repro.gp.hyperparams import HyperParams
 from repro.gp.kernels_math import profile_from_r2, scaled_sqdist
@@ -108,12 +107,12 @@ def ring_kernel_mvm(
         return acc.astype(v_loc.dtype)
 
     spec = P(axes, None)
-    return shard_map(
+    return jax.shard_map(
         local,
         mesh=mesh,
         in_specs=(spec, spec, P(), P()),
         out_specs=spec,
-        check_rep=False,
+        check_vma=False,
     )(x, v, lengthscales, signal)
 
 

@@ -21,7 +21,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.gp.hyperparams import HyperParams, resolve_kind
-from repro.kernels.registry import available_kernels, get_kernel
+from repro.kernels.registry import MVM_PRECISION, available_kernels, get_kernel
 
 
 
@@ -34,13 +34,14 @@ def scaled_sqdist(x1: jax.Array, x2: jax.Array, lengthscales: jax.Array) -> jax.
       (n, m) matrix of ||(x1_i - x2_j)/ell||^2, clamped to >= 0.
 
     Uses the expanded quadratic form so the cross term is a single GEMM
-    (the same contraction the Pallas kernel feeds to the MXU).
+    (the same contraction the Pallas kernel feeds to the MXU), at
+    ``MVM_PRECISION``.
     """
     u = x1 / lengthscales
     v = x2 / lengthscales
     uu = jnp.sum(u * u, axis=-1)  # (n,)
     vv = jnp.sum(v * v, axis=-1)  # (m,)
-    cross = u @ v.T  # (n, m) — MXU-friendly
+    cross = jnp.matmul(u, v.T, precision=MVM_PRECISION)  # (n, m)
     r2 = uu[:, None] + vv[None, :] - 2.0 * cross
     return jnp.maximum(r2, 0.0)
 
@@ -125,7 +126,7 @@ def kernel_mvm_streamed(
     def body(xb):
         r2 = scaled_sqdist(xb, x2, params.lengthscales)
         kb = profile(r2, params.signal)
-        return kb @ v
+        return jnp.matmul(kb, v, precision=MVM_PRECISION)
 
     out = jax.lax.map(body, blocks).reshape(nb * block_rows, v.shape[1])[:n]
     return out[:, 0] if squeeze else out

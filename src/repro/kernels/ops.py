@@ -14,8 +14,10 @@ unit kernel of pre-scaled inputs, and only the per-tile profile evaluation
 differs between kernels. The backward pass is the paper-motivated fusion:
 ONE extra sweep over distance tiles serves every hyperparameter.
 
-On CPU (this container) the kernels run with ``interpret=True``; on TPU the
-same BlockSpecs compile via Mosaic.
+On the CPU platform the kernels run with ``interpret=True``; on every other
+platform the same BlockSpecs compile via Mosaic. The CPU is the only way
+into interpret mode: a TPU that failed to start never silently becomes an
+interpreted kernel.
 """
 from __future__ import annotations
 
@@ -30,7 +32,7 @@ from repro.kernels.tiled import kernel_mvm_bwd_pallas, kernel_mvm_pallas
 
 
 def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+    return jax.default_backend() == "cpu"
 
 
 def _pad_rows(a: jax.Array, mult: int) -> jax.Array:

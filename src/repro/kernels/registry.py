@@ -31,6 +31,14 @@ from typing import Callable, NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 
+# Precision of every contraction in a kernel MVM (distance cross term and
+# the kernel-tile product). On a TPU the default f32 matmul is one bf16
+# pass, which put ~3e-3 normwise relative error on K @ v at pol's shape on a
+# v5e (fp32 products: ~1e-6); the cross term also cancels in
+# ``uu + ww - 2 cross``. HIGHEST keeps the fp32 semantics the solvers and
+# the dense references assume; bf16 tiles are a separate, opt-in choice.
+MVM_PRECISION = jax.lax.Precision.HIGHEST
+
 SQRT3 = 1.7320508075688772
 SQRT5 = 2.23606797749979
 

@@ -35,7 +35,7 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
-from repro.kernels.registry import KernelSpec, get_kernel
+from repro.kernels.registry import MVM_PRECISION, KernelSpec, get_kernel
 
 
 def _dist_tile(u, w):
@@ -43,7 +43,8 @@ def _dist_tile(u, w):
     uu = jnp.sum(u * u, axis=-1, keepdims=True)  # (bm, 1)
     ww = jnp.sum(w * w, axis=-1, keepdims=True)  # (bn, 1)
     cross = jax.lax.dot_general(
-        u, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32
+        u, w, (((1,), (1,)), ((), ())), preferred_element_type=jnp.float32,
+        precision=MVM_PRECISION,
     )
     return jnp.maximum(uu + ww.T - 2.0 * cross, 0.0)
 
@@ -54,7 +55,8 @@ def _mvm_kernel(spec: KernelSpec, u_ref, w_ref, v_ref, out_ref):
     r2 = _dist_tile(u_ref[...], w_ref[...])
     k = spec.kappa_from_r2(r2)
     acc = jax.lax.dot(
-        k.astype(v_ref.dtype), v_ref[...], preferred_element_type=jnp.float32
+        k.astype(v_ref.dtype), v_ref[...], preferred_element_type=jnp.float32,
+        precision=MVM_PRECISION,
     )
 
     @pl.when(j == 0)
@@ -79,11 +81,12 @@ def _mvm_bwd_kernel(spec: KernelSpec, u_ref, w_ref, g_ref, v_ref, du_ref):
     dk = spec.dkappa_dr2(r2)
     e = jax.lax.dot_general(
         g_ref[...], v_ref[...], (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32,
+        preferred_element_type=jnp.float32, precision=MVM_PRECISION,
     )  # (bm, bn) = g v^T
     d_tile = e * dk
     rowsum = jnp.sum(d_tile, axis=1, keepdims=True)  # (bm, 1)
-    dw_contrib = jax.lax.dot(d_tile, w, preferred_element_type=jnp.float32)
+    dw_contrib = jax.lax.dot(d_tile, w, preferred_element_type=jnp.float32,
+                             precision=MVM_PRECISION)
     acc = 2.0 * (rowsum * u - dw_contrib)
 
     @pl.when(j == 0)

@@ -45,6 +45,7 @@ import time
 from typing import NamedTuple, Optional
 
 from repro.configs.gp_iterative import KERNEL_SWEEP, SMOKE, GPArchConfig
+from repro.runtime import enable_compilation_cache
 
 
 class Cell(NamedTuple):
@@ -434,7 +435,9 @@ def run_single_cell(archs, args) -> int:
     import jax
 
     from repro.core import fit
+    from repro.runtime import require_no_cpu_fallback
 
+    require_no_cpu_fallback()
     kind, seed = args.only_cell.rsplit(":", 1)
     seed = int(seed)
     matches = [a for a in archs if a.kind == kind]
@@ -506,6 +509,8 @@ def main(argv=None) -> int:
     ap.add_argument("--expect-one-compile-per-group", action="store_true",
                     help="fail unless retraces == executed groups")
     args = ap.parse_args(argv)
+    # Sets a config value only: the --isolate parent stays off the chip.
+    enable_compilation_cache()
 
     kernels = args.kernels.split(",") if args.kernels else None
     archs = sweep_archs(kernels, args.smoke)

@@ -6,19 +6,28 @@ jax device state.
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape: tuple, axes: tuple):
+    """``jax.make_mesh`` with ``Auto`` axes: shardings propagate through jit
+    as in ``NamedSharding`` placement, instead of the ``Explicit`` default
+    that types every intermediate and rejects gathers without an
+    ``out_sharding``."""
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     """(16, 16) = 256 chips/pod single-pod; (2, 16, 16) = 512 chips 2-pod."""
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_host_mesh():
     """Whatever devices exist locally (smoke tests: 1 CPU device)."""
     n = len(jax.devices())
-    return jax.make_mesh((n, 1), ("data", "model"))
+    return _auto_mesh((n, 1), ("data", "model"))
 
 
 def make_lane_mesh(num_devices: int | None = None):
@@ -32,7 +41,7 @@ def make_lane_mesh(num_devices: int | None = None):
     devices via ``XLA_FLAGS=--xla_force_host_platform_device_count=N``.
     """
     n = num_devices or len(jax.devices())
-    return jax.make_mesh((n,), ("lanes",))
+    return _auto_mesh((n,), ("lanes",))
 
 
 # TPU v5e hardware model for the roofline (per chip).

@@ -15,6 +15,8 @@ import time
 import jax
 import jax.numpy as jnp
 
+from repro.runtime import enable_compilation_cache
+
 
 def serve_lm(args):
     from repro.configs import get_config
@@ -598,6 +600,7 @@ def main(argv=None):
                          "counters, health contract, kill-one-replica "
                          "staleness + burn-rate PAGE), then exit (CI smoke)")
     args = ap.parse_args(argv)
+    enable_compilation_cache()
     if args.arch == "gp-iterative":
         serve_gp(args)
     else:

@@ -18,6 +18,8 @@ import os
 
 import jax
 
+from repro.runtime import enable_compilation_cache
+
 
 def run_gp(args):
     from repro.core import OuterConfig, fit, pick_sgd_learning_rate
@@ -145,6 +147,7 @@ def main(argv=None):
     ap.add_argument("--ckpt-every", type=int, default=0)
     ap.add_argument("--out", default=None)
     args = ap.parse_args(argv)
+    enable_compilation_cache()
     if args.arch == "gp-iterative":
         run_gp(args)
     else:

@@ -61,6 +61,7 @@ def run_worker(cfg: dict) -> None:
     (spawn-pickle friendly). Blocks until SIGTERM/SIGINT, then drains."""
     # Imports happen here, inside the spawned process.
     from repro.obs import trace as obs_trace
+    from repro.runtime import require_no_cpu_fallback
     from repro.serve.cluster.admission import AdmissionController
     from repro.serve.cluster.store import ArtifactPoller, latest_version
     from repro.serve.cluster.transport import ServeFrontend, start_http_server
@@ -73,6 +74,7 @@ def run_worker(cfg: dict) -> None:
     request_log = cfg.get("request_log")
     if request_log:
         obs_trace.configure(path=request_log)
+    require_no_cpu_fallback()
 
     buckets = tuple(cfg.get("buckets", DEFAULT_BUCKETS))
     server = MultiModelServer(
@@ -194,7 +196,17 @@ class ReplicaSupervisor:
 
         Returns the list of endpoint URLs. Raises on timeout or if a
         worker dies during startup (its exitcode is in the message).
+        Refuses to start from a process that holds a TPU: the workers would
+        need the chip it keeps.
         """
+        from repro.runtime import holds_tpu
+
+        if holds_tpu():
+            raise RuntimeError(
+                "this process holds the TPU, so replica workers cannot get "
+                "it; on a TPU serve in-process instead (--http without "
+                "--artifact-store, or repro.serve.BucketedEngine)"
+            )
         os.makedirs(self.run_dir, exist_ok=True)
         for i in range(self.num_replicas):
             self._spawn(i)

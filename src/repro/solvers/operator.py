@@ -31,6 +31,7 @@ from repro.gp.kernels_math import (
     regularised_kernel_matrix,
     scaled_sqdist,
 )
+from repro.kernels.registry import MVM_PRECISION
 
 
 def kernel_mvm_tiled(
@@ -69,7 +70,7 @@ def kernel_mvm_tiled(
             xc, vc = xcvc
             r2 = scaled_sqdist(xr, xc, params.lengthscales)
             kb = profile(r2, params.signal)
-            return acc + kb @ vc, None
+            return acc + jnp.matmul(kb, vc, precision=MVM_PRECISION), None
 
         acc0 = jnp.zeros((bm, s), dtype=v.dtype)
         acc, _ = jax.lax.scan(col_step, acc0, (x2b, vb))

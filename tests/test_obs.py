@@ -149,8 +149,9 @@ def test_ring_buffer_matches_final_residuals(name):
     last = hist[(iters - 1) % 16]
     np.testing.assert_allclose(last, [float(res.res_y), float(res.res_z)],
                                rtol=1e-6)
-    # Unwritten slots stay NaN.
-    written = np.isfinite(hist[:, 0]).sum()
+    # Unwritten slots stay NaN. A written slot may hold inf: at this step
+    # size the SGD toy solve diverges, and its last residuals are inf.
+    written = (~np.isnan(hist[:, 0])).sum()
     assert written == min(iters, 16)
 
     # Off path: no history, identical solution bits.

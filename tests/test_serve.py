@@ -157,8 +157,13 @@ def test_refresh_then_swap_preserves_old_predictions(fitted, model):
     points beyond solver tolerance; the swap is atomic on the engine."""
     engine = BucketedEngine(model, buckets=(32,), bm=64, bn=64)
     before = engine.submit(fitted["xq"])
-    key = jax.random.PRNGKey(9)
-    x_new, y_new = make_gp_regression(key, 24, 2, noise=0.2)
+    # New observations of the SAME latent function as the fixture (same
+    # key, so the same RFF prior draw), at fresh inputs. A different key
+    # draws a different function, whose points may rightly move old-point
+    # predictions by several std.
+    x_all, y_all = make_gp_regression(jax.random.PRNGKey(0), 160 + 24, 2,
+                                      noise=0.2)
+    x_new, y_new = x_all[160:], y_all[160:]
     online = OnlineGP(fitted["x"], fitted["y"], fitted["state"], fitted["cfg"])
     online.append(x_new, y_new)
     report = online.refresh_into(engine, budget_epochs=200.0)
