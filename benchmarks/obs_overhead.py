@@ -142,9 +142,12 @@ def main(small: bool = True, out_dir: str = "artifacts/bench"):
             f"({frac * 100:.1f}%) exceeds budget {budget * 1e3:.1f}ms")
 
     events = sum(1 for _ in open(log_path))
-    # Each logged fit emits `steps` solve_step events + one fit_done. Logged
-    # fits: off_log + on warmups, repeats x (off_log + on), the retrace probe.
-    expected = (2 * (repeats + 1) + 1) * (steps + 1)
+    # Each logged fit emits `steps` solve_step events + one fit_done, and
+    # its fit.* spans: fit.init, fit.finish and a fit.chunk + fit.metrics
+    # pair per round of fit's default 8 steps. Logged fits: off_log + on
+    # warmups, repeats x (off_log + on), the retrace probe.
+    spans = 2 + 2 * -(-steps // 8)
+    expected = (2 * (repeats + 1) + 1) * (steps + 1 + spans)
     if events != expected:
         raise SystemExit(f"[obs-overhead] expected {expected} events, "
                          f"logged {events}")

@@ -36,12 +36,13 @@ EVENT_SCHEMAS: Dict[str, frozenset] = {
     "request": frozenset({"method", "path", "status", "dur_ms"}),
     "admission": frozenset({"outcome", "rows", "priority", "retry_after_s",
                             "inflight"}),
-    "span": frozenset({"span", "dur_ms", "error", "rows", "bucket"}),
+    "span": frozenset({"span", "dur_ms", "error", "rows", "bucket",
+                       "steps"}),
     "solve_step": frozenset({"step", "solver", "lane", "res_y", "res_z",
                              "iters", "epochs", "step_time_s",
                              "res_history"}),
     "fit_done": frozenset({"solver", "num_steps", "total_iters",
-                           "total_epochs", "wall_time_s", "solver_time_s"}),
+                           "total_epochs", "wall_time_s"}),
     "budget_decision": frozenset({"step", "solver", "lane", "alloc",
                                   "pred_to_tol", "realised", "res", "slope",
                                   "noise", "perturbation", "grad_noise",

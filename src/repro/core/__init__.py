@@ -39,7 +39,6 @@ from repro.core.predict import (
     predictive_metrics,
 )
 from repro.core.driver import (
-    GRAD_EPOCH_EQUIV,
     SGD_DIVERGENCE_THRESHOLD,
     FitResult,
     evaluate,
@@ -61,7 +60,7 @@ __all__ = [
     "Predictions", "correction_matrix", "mean_only_predict",
     "pathwise_predict", "pathwise_predict_from_correction",
     "predictive_metrics",
-    "GRAD_EPOCH_EQUIV", "SGD_DIVERGENCE_THRESHOLD",
+    "SGD_DIVERGENCE_THRESHOLD",
     "FitResult", "evaluate", "fit", "fit_batch", "init_hypers_heuristic",
     "pick_sgd_learning_rate",
 ]

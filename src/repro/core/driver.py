@@ -34,6 +34,8 @@ from repro.distributed.checkpoint import (
     save_checkpoint,
 )
 from repro.gp.hyperparams import HyperParams
+from repro.obs import scopes
+from repro.obs.trace import span
 from repro.solvers import (
     HOperator,
     SolverNumerics,
@@ -57,13 +59,6 @@ SGD_LR_GRID = [5.0, 10.0, 20.0, 30.0, 50.0, 60.0, 70.0, 80.0, 90.0, 100.0]
 # starting norm — the iteration is expanding, not contracting.
 SGD_DIVERGENCE_THRESHOLD = 4.0
 
-# Epoch-equivalents charged to gradient assembly when splitting a measured
-# step time into solve vs grad/Adam time. mll_grad_estimate differentiates
-# one tiled kernel MVM: the forward pass touches every entry of H once
-# (1 epoch-equivalent) and the reverse pass re-streams the tiles for the
-# cotangents (~2 more). Adam and target building are O(n) and ignored.
-GRAD_EPOCH_EQUIV = 3.0
-
 
 @dataclass
 class FitResult:
@@ -72,8 +67,6 @@ class FitResult:
     state: OuterState
     history: dict  # str -> np.ndarray over steps
     wall_time_s: float
-    solver_time_s: float  # estimated inner-solve share (epoch accounting)
-    grad_time_s: float = 0.0  # estimated grad-assembly + Adam share
 
 
 def pick_sgd_learning_rate(
@@ -174,7 +167,7 @@ def _empty_history() -> dict[str, list]:
         "res_y": [], "res_z": [], "iters": [], "epochs": [],
         "hypers": [], "grad_norm": [], "data_fit": [],
         "eval_step": [], "eval_rmse": [], "eval_llh": [],
-        "step_time_s": [], "solver_frac_iters": [],
+        "step_time_s": [],
     }
 
 
@@ -193,14 +186,13 @@ def _round_size(step: int, num_steps: int, steps_per_round: int,
 
 def _append_round(history: dict, metrics: dict, dt: float, k: int,
                   lane: Optional[int] = None,
-                  event_log=None, solver: str = "") -> float:
+                  event_log=None, solver: str = "") -> None:
     """Append one scan round's stacked metrics (leading axis = k steps) to
-    the per-step history lists. Returns the round's estimated solve time.
+    the per-step history lists.
 
-    The solve vs grad/Adam split comes from epoch accounting (the scan runs
-    on-device, so there is no per-phase host timer): each step's solver work
-    is ``epochs`` epoch-equivalents against :data:`GRAD_EPOCH_EQUIV` for
-    gradient assembly; ``solver_frac_iters`` records that per-step fraction.
+    The round's time splits into solve, gradient and the rest only on the
+    device's clock: a profile of the fit reads it from the ``gp.*`` scopes
+    (``repro.obs.scopes``, ``docs/observability.md``).
 
     When ``event_log`` (a :class:`repro.obs.trace.EventLog`) is given, one
     structured ``solve_step`` event is emitted per outer step — the host-side
@@ -220,7 +212,6 @@ def _append_round(history: dict, metrics: dict, dt: float, k: int,
         return np.asarray(a[:, lane] if lane is not None else a, dtype=dtype)
 
     epochs = col("epochs", np.float64)
-    frac = epochs / (epochs + GRAD_EPOCH_EQUIV)
     steps = col("step", int)
     iters = col("iters", int)
     res_y, res_z = col("res_y"), col("res_z")
@@ -232,7 +223,6 @@ def _append_round(history: dict, metrics: dict, dt: float, k: int,
     history["grad_norm"].extend(col("grad_norm"))
     history["data_fit"].extend(col("data_fit"))
     history["step_time_s"].extend([dt / k] * k)
-    history["solver_frac_iters"].extend(frac)
     rings = None
     if "res_history" in metrics:
         from repro.solvers.base import unroll_history
@@ -264,7 +254,6 @@ def _append_round(history: dict, metrics: dict, dt: float, k: int,
                                    name[len("budget_"):]: float(vals[j])
                                    for name, vals in budget_cols.items()
                                })
-    return float(np.sum(dt / k * frac))
 
 
 def fit(
@@ -327,46 +316,56 @@ def fit(
     ``cfg.num_steps`` here. History gains the ``budget_*`` columns and
     ``event_log`` a per-step ``budget_decision`` event; ``None`` (default)
     keeps ``fit`` bit-identical to the fixed-budget behaviour.
+
+    The driver's phases are ``fit.*`` spans (``repro.obs.scopes``):
+    ``fit.init``, one ``fit.chunk`` per scan round (``steps=k``),
+    ``fit.metrics``, ``fit.eval`` and ``fit.ckpt`` where enabled, and
+    ``fit.finish``. They go to ``event_log`` (else to the process-wide log,
+    if one is configured) and, under a profiler session, onto the
+    profile's host plane.
     """
     key = key if key is not None else jax.random.PRNGKey(0)
     policy = budget_policy
     if policy is not None:
         _require_history(cfg)  # eager: fail before any compile work
         policy = resolve_horizon(policy, cfg.num_steps)
-    state = init_outer_state(key, cfg, x, init_params=init_params)
-    start_step = 0
-    if ckpt_dir and resume and latest_step(ckpt_dir) is not None:
-        state, start_step = restore_checkpoint(ckpt_dir, state)
+    with span(scopes.FIT_INIT, log=event_log):
+        state = init_outer_state(key, cfg, x, init_params=init_params)
+        start_step = 0
+        if ckpt_dir and resume and latest_step(ckpt_dir) is not None:
+            state, start_step = restore_checkpoint(ckpt_dir, state)
 
     history = _empty_history()
     t0 = time.perf_counter()
-    solver_time = 0.0
 
     step = start_step
     while step < cfg.num_steps:
         k = _round_size(step, cfg.num_steps, steps_per_round,
                         eval_every if x_test is not None else 0,
                         ckpt_every if ckpt_dir else 0)
-        ts = time.perf_counter()
-        if policy is None:
-            state, metrics = outer_scan(state, x, y, cfg, k,
-                                        numerics=numerics)
-        else:
-            # The policy rides the scan carry WITHIN a chunk and is handed
-            # back in explicitly ACROSS chunks — EMAs, anneal counter and
-            # epoch pool are invariant to the chunking.
-            (state, policy), metrics = outer_scan(
-                state, x, y, cfg, k, numerics=numerics, budget=policy
-            )
-        jax.block_until_ready(state.carry_v)
-        dt = time.perf_counter() - ts
-        solver_time += _append_round(history, metrics, dt, k,
-                                     event_log=event_log,
-                                     solver=cfg.solver.name)
+        with span(scopes.FIT_CHUNK, log=event_log, steps=k):
+            ts = time.perf_counter()
+            if policy is None:
+                state, metrics = outer_scan(state, x, y, cfg, k,
+                                            numerics=numerics)
+            else:
+                # The policy rides the scan carry WITHIN a chunk and is
+                # handed back in explicitly ACROSS chunks — EMAs, anneal
+                # counter and epoch pool are invariant to the chunking.
+                (state, policy), metrics = outer_scan(
+                    state, x, y, cfg, k, numerics=numerics, budget=policy
+                )
+            jax.block_until_ready(state.carry_v)
+            dt = time.perf_counter() - ts
+        with span(scopes.FIT_METRICS, log=event_log):
+            _append_round(history, metrics, dt, k, event_log=event_log,
+                          solver=cfg.solver.name)
         step += k
 
         if eval_every and x_test is not None and step % eval_every == 0:
-            m = evaluate(x, state, cfg, x_test, y_test, numerics=numerics)
+            with span(scopes.FIT_EVAL, log=event_log):
+                m = evaluate(x, state, cfg, x_test, y_test,
+                             numerics=numerics)
             history["eval_step"].append(step)
             history["eval_rmse"].append(m["rmse"])
             history["eval_llh"].append(m["llh"])
@@ -374,7 +373,8 @@ def fit(
                 print(f"[fit] step {step}: rmse={m['rmse']:.4f} llh={m['llh']:.4f}")
 
         if ckpt_dir and ckpt_every and step % ckpt_every == 0:
-            save_checkpoint(ckpt_dir, step, state)
+            with span(scopes.FIT_CKPT, log=event_log):
+                save_checkpoint(ckpt_dir, step, state)
 
         if verbose:
             print(
@@ -383,20 +383,19 @@ def fit(
                 f"iters={history['iters'][-1]} ({dt:.2f}s/{k} steps)"
             )
 
-    if ckpt_dir:
-        save_checkpoint(ckpt_dir, cfg.num_steps, state)
-    wall = time.perf_counter() - t0
-    hist = {k_: np.asarray(v) for k_, v in history.items()}
-    if event_log is not None:
-        event_log.emit(
-            "fit_done", solver=cfg.solver.name, num_steps=cfg.num_steps,
-            total_iters=int(np.sum(hist["iters"])),
-            total_epochs=float(np.sum(hist["epochs"])),
-            wall_time_s=wall, solver_time_s=solver_time,
-        )
-    return FitResult(state=state, history=hist, wall_time_s=wall,
-                     solver_time_s=solver_time,
-                     grad_time_s=float(np.sum(hist["step_time_s"])) - solver_time)
+    with span(scopes.FIT_FINISH, log=event_log):
+        if ckpt_dir:
+            save_checkpoint(ckpt_dir, cfg.num_steps, state)
+        wall = time.perf_counter() - t0
+        hist = {k_: np.asarray(v) for k_, v in history.items()}
+        if event_log is not None:
+            event_log.emit(
+                "fit_done", solver=cfg.solver.name, num_steps=cfg.num_steps,
+                total_iters=int(np.sum(hist["iters"])),
+                total_epochs=float(np.sum(hist["epochs"])),
+                wall_time_s=wall,
+            )
+    return FitResult(state=state, history=hist, wall_time_s=wall)
 
 
 def fit_batch(
@@ -438,9 +437,9 @@ def fit_batch(
     ``steps_per_round <= 0`` (default) scans all steps in one dispatch.
     Checkpointing is not supported here; per-lane eval runs once at the end
     when ``x_test`` is given. Returned per-lane ``wall_time_s`` is the
-    shared wall clock divided by B (the amortised per-scenario cost);
-    ``solver_time_s`` splits each lane's share by its own epoch accounting.
-    ``event_log`` emits lane-tagged ``solve_step`` events (see :func:`fit`).
+    shared wall clock divided by B (the amortised per-scenario cost).
+    ``event_log`` emits lane-tagged ``solve_step`` events and the same
+    ``fit.*`` spans as :func:`fit`, one per round for all lanes.
 
     ``budget_policy`` turns on per-lane adaptive budgets: scalar leaves are
     broadcast to every lane, already-(B,)-stacked leaves give each lane its
@@ -451,82 +450,88 @@ def fit_batch(
     """
     keys = jnp.asarray(keys)
     lanes = keys.shape[0]
-    states = init_outer_state_lanes(keys, cfg, x, init_params=init_params)
-    assert num_lanes(states) == lanes
-    if numerics is not None:
-        numerics = broadcast_numerics(numerics, lanes)
-    policy = budget_policy
-    if policy is not None:
-        _require_history(cfg)  # eager: fail before any compile work
-        policy = broadcast_policy(resolve_horizon(policy, cfg.num_steps),
-                                  lanes)
-    if mesh is not None:
-        from jax.sharding import NamedSharding, PartitionSpec
-
-        ndev = mesh.devices.size
-        if lanes % ndev != 0:
-            raise ValueError(
-                f"lanes={lanes} must be a multiple of the lane-mesh device "
-                f"count {ndev} (pad the grid or drop --shard-lanes)"
-            )
-        lane_sharding = NamedSharding(mesh, PartitionSpec(mesh.axis_names[0]))
-        replicated = NamedSharding(mesh, PartitionSpec())
-        states = jax.device_put(states, lane_sharding)
-        x = jax.device_put(x, replicated)
-        y = jax.device_put(y, replicated)
+    with span(scopes.FIT_INIT, log=event_log):
+        states = init_outer_state_lanes(keys, cfg, x,
+                                        init_params=init_params)
+        assert num_lanes(states) == lanes
         if numerics is not None:
-            numerics = jax.device_put(numerics, lane_sharding)
+            numerics = broadcast_numerics(numerics, lanes)
+        policy = budget_policy
         if policy is not None:
-            policy = jax.device_put(policy, lane_sharding)
+            _require_history(cfg)  # eager: fail before any compile work
+            policy = broadcast_policy(
+                resolve_horizon(policy, cfg.num_steps), lanes)
+        if mesh is not None:
+            from jax.sharding import NamedSharding, PartitionSpec
+
+            ndev = mesh.devices.size
+            if lanes % ndev != 0:
+                raise ValueError(
+                    f"lanes={lanes} must be a multiple of the lane-mesh "
+                    f"device count {ndev} (pad the grid or drop "
+                    f"--shard-lanes)"
+                )
+            lane_sharding = NamedSharding(mesh,
+                                          PartitionSpec(mesh.axis_names[0]))
+            replicated = NamedSharding(mesh, PartitionSpec())
+            states = jax.device_put(states, lane_sharding)
+            x = jax.device_put(x, replicated)
+            y = jax.device_put(y, replicated)
+            if numerics is not None:
+                numerics = jax.device_put(numerics, lane_sharding)
+            if policy is not None:
+                policy = jax.device_put(policy, lane_sharding)
 
     histories = [_empty_history() for _ in range(lanes)]
     t0 = time.perf_counter()
-    solver_times = [0.0] * lanes
 
     step = 0
     while step < cfg.num_steps:
         k = _round_size(step, cfg.num_steps, steps_per_round)
-        ts = time.perf_counter()
-        if policy is None:
-            states, metrics = outer_scan(states, x, y, cfg, k, lanes=True,
-                                         numerics=numerics)
-        else:
-            (states, policy), metrics = outer_scan(
-                states, x, y, cfg, k, lanes=True, numerics=numerics,
-                budget=policy,
-            )
-        jax.block_until_ready(states.carry_v)
-        dt = time.perf_counter() - ts
-        # One device->host transfer per metric, not one per metric per lane.
-        metrics = {name: np.asarray(v) for name, v in metrics.items()}
-        for lane in range(lanes):
-            solver_times[lane] += _append_round(
-                histories[lane], metrics, dt / lanes, k, lane=lane,
-                event_log=event_log, solver=cfg.solver.name)
+        with span(scopes.FIT_CHUNK, log=event_log, steps=k):
+            ts = time.perf_counter()
+            if policy is None:
+                states, metrics = outer_scan(states, x, y, cfg, k,
+                                             lanes=True, numerics=numerics)
+            else:
+                (states, policy), metrics = outer_scan(
+                    states, x, y, cfg, k, lanes=True, numerics=numerics,
+                    budget=policy,
+                )
+            jax.block_until_ready(states.carry_v)
+            dt = time.perf_counter() - ts
+        with span(scopes.FIT_METRICS, log=event_log):
+            # One device->host transfer per metric, not one per metric per
+            # lane.
+            metrics = {name: np.asarray(v) for name, v in metrics.items()}
+            for lane in range(lanes):
+                _append_round(histories[lane], metrics, dt / lanes, k,
+                              lane=lane, event_log=event_log,
+                              solver=cfg.solver.name)
         step += k
         if verbose:
             print(f"[fit_batch] step {step}/{cfg.num_steps} x {lanes} lanes "
                   f"({dt:.2f}s/{k} steps)")
 
     wall = time.perf_counter() - t0
-    results = []
-    for lane in range(lanes):
-        lane_state = unstack_state(states, lane)
-        hist = histories[lane]
-        if x_test is not None:
-            lane_num = (None if numerics is None
-                        else jax.tree.map(lambda v: v[lane], numerics))
-            m = evaluate(x, lane_state, cfg, x_test, y_test, numerics=lane_num)
-            hist["eval_step"].append(cfg.num_steps)
-            hist["eval_rmse"].append(m["rmse"])
-            hist["eval_llh"].append(m["llh"])
-        hist = {k_: np.asarray(v) for k_, v in hist.items()}
-        results.append(FitResult(
-            state=lane_state, history=hist, wall_time_s=wall / lanes,
-            solver_time_s=solver_times[lane],
-            grad_time_s=float(np.sum(hist["step_time_s"])) - solver_times[lane],
-        ))
-    return results
+    lane_states = [unstack_state(states, lane) for lane in range(lanes)]
+    if x_test is not None:
+        with span(scopes.FIT_EVAL, log=event_log):
+            for lane, lane_state in enumerate(lane_states):
+                lane_num = (None if numerics is None
+                            else jax.tree.map(lambda v: v[lane], numerics))
+                m = evaluate(x, lane_state, cfg, x_test, y_test,
+                             numerics=lane_num)
+                histories[lane]["eval_step"].append(cfg.num_steps)
+                histories[lane]["eval_rmse"].append(m["rmse"])
+                histories[lane]["eval_llh"].append(m["llh"])
+    with span(scopes.FIT_FINISH, log=event_log):
+        return [
+            FitResult(state=lane_state,
+                      history={k_: np.asarray(v) for k_, v in hist.items()},
+                      wall_time_s=wall / lanes)
+            for lane_state, hist in zip(lane_states, histories)
+        ]
 
 
 def evaluate(

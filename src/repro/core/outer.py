@@ -39,6 +39,7 @@ from repro.core.estimators import (
 )
 from repro.core.gradients import mll_grad_estimate
 from repro.gp.hyperparams import HyperParams
+from repro.obs import scopes
 from repro.solvers import (
     HOperator,
     SolverConfig,
@@ -255,10 +256,10 @@ def _outer_step(
     key, ksolve, kprobe = jax.random.split(state.key, 3)
 
     probes = state.probes
-    if not cfg.warm_start:
-        probes = _resample_probes(kprobe, probes, x)
-
-    targets = build_system_targets(probes, x, y, state.params)
+    with jax.named_scope(scopes.TARGETS):
+        if not cfg.warm_start:
+            probes = _resample_probes(kprobe, probes, x)
+        targets = build_system_targets(probes, x, y, state.params)
     v0 = state.carry_v if cfg.warm_start else None
 
     op = HOperator(
@@ -269,15 +270,18 @@ def _outer_step(
     # precedence (OuterConfig.kind > SolverConfig.kind) holds; solve()'s
     # conflict check then only fires for hand-built operator/config pairs.
     scfg = cfg.solver if cfg.solver.kind == kind else replace(cfg.solver, kind=kind)
-    res = solve(op, targets, v0, scfg, key=ksolve, numerics=numerics)
+    with jax.named_scope(scopes.SOLVE):
+        res = solve(op, targets, v0, scfg, key=ksolve, numerics=numerics)
 
-    grads, aux = mll_grad_estimate(
-        x, y, state.params, res.v, targets, cfg.estimator,
-        kind=kind, bm=cfg.bm, bn=cfg.bn,
-    )
-    new_params, new_adam = adam_update(
-        grads, state.adam, state.params, cfg.adam, maximize=True
-    )
+    with jax.named_scope(scopes.GRAD):
+        grads, aux = mll_grad_estimate(
+            x, y, state.params, res.v, targets, cfg.estimator,
+            kind=kind, bm=cfg.bm, bn=cfg.bn,
+        )
+    with jax.named_scope(scopes.ADAM):
+        new_params, new_adam = adam_update(
+            grads, state.adam, state.params, cfg.adam, maximize=True
+        )
 
     new_state = OuterState(
         params=new_params,

@@ -260,15 +260,12 @@ def _cell_record(cell: Cell, res, mode: str, group_size: int) -> dict:
         "mode": mode,
         "lanes": group_size,
         "wall_time_s": res.wall_time_s,
-        "solver_time_s": res.solver_time_s,
-        "grad_time_s": res.grad_time_s,
         "final_hypers": [float(v) for v in hist["hypers"][-1]],
         "history": {
             "res_y": [float(v) for v in hist["res_y"]],
             "res_z": [float(v) for v in hist["res_z"]],
             "iters": [int(v) for v in hist["iters"]],
             "epochs": [float(v) for v in hist["epochs"]],
-            "solver_frac_iters": [float(v) for v in hist["solver_frac_iters"]],
         },
     }
 

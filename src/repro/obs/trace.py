@@ -23,6 +23,7 @@ import json
 import os
 import re
 import secrets
+import sys
 import threading
 import time
 from typing import Optional
@@ -238,6 +239,19 @@ class Span:
         self.dur_ms: Optional[float] = None
 
 
+def _profiler_annotation(name: str, fields: dict):
+    """``jax.profiler.TraceAnnotation`` of the span, with its scalar fields
+    as stats, where JAX is already loaded; else a null context. JAX is
+    never imported from here, so this module stays stdlib-only."""
+    jax = sys.modules.get("jax")
+    profiler = getattr(jax, "profiler", None)
+    if profiler is None:
+        return contextlib.nullcontext()
+    stats = {k: v for k, v in fields.items()
+             if isinstance(v, (bool, int, float, str))}
+    return profiler.TraceAnnotation(name, **stats)
+
+
 @contextlib.contextmanager
 def span(name: str, log: Optional[EventLog] = None,
          trace_id: Optional[str] = None, **fields):
@@ -248,11 +262,17 @@ def span(name: str, log: Optional[EventLog] = None,
     the yielded :class:`Span` while it is open. An exception inside the
     block still emits the span, with ``error`` set to the exception type,
     then propagates.
+
+    Where JAX is loaded, the block is also a ``jax.profiler.TraceAnnotation``
+    of the same name, its scalar ``fields`` as stats: under a profiler
+    session the span is an event of the profile's host plane, on the clock
+    the device planes share. Without a session that costs one check.
     """
     sp = Span(name, dict(fields))
     error = None
     try:
-        yield sp
+        with _profiler_annotation(name, fields):
+            yield sp
     except BaseException as e:
         error = type(e).__name__
         raise

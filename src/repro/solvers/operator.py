@@ -32,6 +32,7 @@ from repro.gp.kernels_math import (
     scaled_sqdist,
 )
 from repro.kernels.registry import MVM_PRECISION
+from repro.obs import scopes
 
 
 def kernel_mvm_tiled(
@@ -133,7 +134,8 @@ class HOperator:
         squeeze = v.ndim == 1
         if squeeze:
             v = v[:, None]
-        out = self._kernel_mvm(v) + self.noise_var * v
+        with jax.named_scope(scopes.MVM):
+            out = self._kernel_mvm(v) + self.noise_var * v
         return out[:, 0] if squeeze else out
 
     # -- partial access (AP / SGD / pivoted Cholesky) -----------------------

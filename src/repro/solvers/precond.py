@@ -18,6 +18,7 @@ from typing import NamedTuple
 import jax
 import jax.numpy as jnp
 
+from repro.obs import scopes
 from repro.solvers.operator import HOperator
 
 _JITTER = 1e-10
@@ -65,9 +66,10 @@ class Preconditioner(NamedTuple):
 
     def apply(self, r: jax.Array) -> jax.Array:
         """P^{-1} @ r for r of shape (n, t)."""
-        ltr = self.l.T @ r  # (k, t)
-        inner = jax.scipy.linalg.cho_solve((self.chol_inner, True), ltr)
-        return (r - self.l @ inner) / self.noise_var
+        with jax.named_scope(scopes.PRECOND):
+            ltr = self.l.T @ r  # (k, t)
+            inner = jax.scipy.linalg.cho_solve((self.chol_inner, True), ltr)
+            return (r - self.l @ inner) / self.noise_var
 
 
 def identity_preconditioner(n: int, dtype=jnp.float32) -> Preconditioner:
@@ -119,9 +121,9 @@ def build_preconditioner(op: HOperator, rank: int) -> Preconditioner:
     rank = min(rank, op.n)
     if rank <= 0:
         return identity_preconditioner(op.n, dtype=op.x.dtype)
-    l = pivoted_cholesky(op, rank)
-    inner = op.noise_var * jnp.eye(rank, dtype=l.dtype) + l.T @ l
-    inner = inner + jitter * jnp.eye(rank, dtype=l.dtype)
-    return Preconditioner(
-        l=l, chol_inner=jnp.linalg.cholesky(inner), noise_var=op.noise_var
-    )
+    with jax.named_scope(scopes.PRECOND):
+        l = pivoted_cholesky(op, rank)
+        inner = op.noise_var * jnp.eye(rank, dtype=l.dtype) + l.T @ l
+        inner = inner + jitter * jnp.eye(rank, dtype=l.dtype)
+        chol_inner = jnp.linalg.cholesky(inner)
+    return Preconditioner(l=l, chol_inner=chol_inner, noise_var=op.noise_var)

@@ -192,24 +192,6 @@ def test_fit_batch_matches_single_fits(outer_problem):
                                    rtol=1e-2, atol=1e-5)
 
 
-def test_fit_populates_solver_frac_and_time_split(outer_problem):
-    """Regression for the silent-empty ``solver_frac_iters`` history key and
-    the whole-step ``solver_time_s``: the fraction is populated per step in
-    (0, 1], and solve + grad/Adam time partition the measured step time."""
-    x, y = outer_problem
-    cfg = OuterConfig(estimator="pathwise", warm_start=True, num_steps=4,
-                      **OUTER_CFG)
-    r = fit(x, y, cfg, key=jax.random.PRNGKey(0))
-    frac = r.history["solver_frac_iters"]
-    assert frac.shape == (4,)
-    assert np.all(frac > 0.0) and np.all(frac <= 1.0)
-    total = float(np.sum(r.history["step_time_s"]))
-    assert r.solver_time_s > 0.0 and r.grad_time_s > 0.0
-    np.testing.assert_allclose(r.solver_time_s + r.grad_time_s, total,
-                               rtol=1e-6)
-    assert r.solver_time_s <= r.wall_time_s
-
-
 def test_sgd_divergence_threshold_constant_and_grid_search(outer_problem):
     """The magic `2.0 * 2.0` is now the named, documented constant; the grid
     search keeps the largest stable lr and rejects a diverging one."""

@@ -4,6 +4,8 @@ parity), and end-to-end trace propagation through a 2-replica cluster."""
 import io
 import json
 import os
+import subprocess
+import sys
 import time
 
 import jax
@@ -121,6 +123,46 @@ def test_module_emit_noop_until_configured(tmp_path):
     finally:
         obs_trace.configure()
     assert obs_trace.emit("ignored") is None
+
+
+def test_span_is_a_profiler_host_event_with_its_fields_as_stats():
+    """Under a profiler session a span lands on the profile's host plane,
+    the clock the device planes share, its scalar fields as stats."""
+    from jax.profiler import ProfileData
+    from jaxlib import _profiler
+
+    options = _profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    session = _profiler.ProfilerSession(options)
+    buf = io.StringIO()
+    with obs_trace.span("fit.chunk", log=obs_trace.EventLog(stream=buf),
+                        steps=8, solver="cg", skipped=[1, 2]):
+        jax.block_until_ready(jnp.ones(4) * 2)
+    profile = ProfileData.from_serialized_xspace(session.stop())
+    found = [dict(ev.stats) for plane in profile.planes
+             if plane.name == "/host:CPU" for line in plane.lines
+             for ev in line.events if ev.name == "fit.chunk"]
+    assert len(found) == 1
+    assert found[0]["steps"] == 8 and found[0]["solver"] == "cg"
+    assert "skipped" not in found[0]  # only scalars become stats
+    (event,) = [json.loads(line) for line in buf.getvalue().splitlines()]
+    assert event["span"] == "fit.chunk" and event["skipped"] == [1, 2]
+
+
+@pytest.mark.parametrize("module", ["repro.obs", "repro.obs.scopes"])
+def test_obs_imports_and_spans_without_jax(module):
+    """Replicas and tools/ import repro.obs stdlib-only: neither importing
+    it nor opening a span loads JAX."""
+    src = os.path.join(os.path.dirname(__file__), os.pardir, "src")
+    code = (f"import sys, {module}\n"
+            "from repro.obs import trace\n"
+            "with trace.span('fit.chunk', steps=1):\n"
+            "    pass\n"
+            "assert 'jax' not in sys.modules, 'jax was imported'\n")
+    env = dict(os.environ, PYTHONPATH=os.path.abspath(src))
+    done = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0, done.stderr
 
 
 # -- solver residual rings ----------------------------------------------------

@@ -146,8 +146,7 @@ def print_residual_summary(events):
             print(f"fit_done: solver={ev.get('solver')} "
                   f"steps={ev.get('num_steps')} iters={ev.get('total_iters')} "
                   f"epochs={ev.get('total_epochs'):.1f} "
-                  f"wall={ev.get('wall_time_s'):.2f}s "
-                  f"solver_time={ev.get('solver_time_s'):.2f}s")
+                  f"wall={ev.get('wall_time_s'):.2f}s")
 
 
 def fleet_logs(fleet_dir):
