@@ -8,27 +8,35 @@ gradient estimate for every hyperparameter is a sum of quadratic forms
 with (u_j, w_j) = (v_j, z_j) for the standard estimator (eq. 6) and
 (v_j, v_j) for the pathwise estimator (eq. 9).
 
-TPU/JAX adaptation (documented in DESIGN.md §3): instead of materialising the
-d+2 matrices dH/dtheta_k and running one MVM each (the GPyTorch/CUDA
-pattern), we differentiate the *scalar*
+TPU/JAX adaptation: instead of materialising the d+2 matrices dH/dtheta_k
+and running one MVM each (the GPyTorch/CUDA pattern), we differentiate the
+*scalar*
 
-    S(theta) = sum_t c_t * a_t^T H(theta) b_t
+    S(theta) = sum_t w_t a_t^T H(theta) b_t
 
-through the tiled kernel MVM with the solution vectors stop-gradiented.
-One reverse-mode pass yields every hyperparameter's gradient, sharing all
-kernel-distance tiles across hypers — the same fusion the Pallas quadform
-kernel performs explicitly in one sweep over tiles.
+with the solution vectors stop-gradiented. Its kernel part and that part's
+gradient wrt (lengthscales, signal) come from ONE sweep over (bm x bn)
+kernel tiles (``_kernel_quadratic``, a ``jax.custom_vjp``): each tile is
+recomputed from its row and column blocks of ``u = x / ell`` and reduced at
+once, so live memory is O(bm * bn) and nothing of size n^2 is stored for
+reverse mode. Per tile, with ``C = (a_r * w) b_c^T`` and
+``G = kappa'(r2) * C``, the sweep accumulates ``sum kappa(r2) * C`` and, per
+input dimension k, ``sum_ij G_ij (u_ik - u_jk)^2`` in its expanded form (the
+algebra reverse-mode AD through the tiled MVM performs). Plain JAX AD keeps
+the softplus of the raw hyperparameters and the noise term.
 """
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 
 from repro.core.estimators import PATHWISE, STANDARD
-from repro.gp.hyperparams import HyperParams
-from repro.solvers.operator import kernel_mvm_tiled
+from repro.gp.hyperparams import HyperParams, resolve_kind
+from repro.gp.kernels_math import scaled_sqdist
+from repro.kernels.registry import _R2_FLOOR, MVM_PRECISION, get_kernel
 
 
 class GradAux(NamedTuple):
@@ -36,6 +44,79 @@ class GradAux(NamedTuple):
 
     data_fit: jax.Array  # -1/2 y^T v_y (the quadratic MLL term, for logging)
     quad_value: jax.Array  # value of the surrogate S (diagnostic)
+
+
+def _tile_sweep(lengthscales, x, aw, b, kind, bm, bn):
+    """(sum_ij kappa_ij C_ij, T) over all tiles, C = aw b^T, never stored.
+
+    T_k = sum_ij kappa'_ij C_ij (u_ik - u_jk)^2 with u = x / lengthscales,
+    in the expanded form ``u_i^2 rowsum + u_j^2 colsum - 2 u_i (G u_c)_i``.
+    Zero-padded rows of ``aw`` and ``b`` make C, and so the padded tiles'
+    contributions, exactly zero.
+    """
+    n, d = x.shape
+    t = b.shape[1]
+    bm = min(bm, n)
+    bn = min(bn, n)
+    nb_m = -(-n // bm)
+    nb_n = -(-n // bn)
+    xr = jnp.pad(x, ((0, nb_m * bm - n), (0, 0))).reshape(nb_m, bm, d)
+    ar = jnp.pad(aw, ((0, nb_m * bm - n), (0, 0))).reshape(nb_m, bm, t)
+    xc = jnp.pad(x, ((0, nb_n * bn - n), (0, 0))).reshape(nb_n, bn, d)
+    bc = jnp.pad(b, ((0, nb_n * bn - n), (0, 0))).reshape(nb_n, bn, t)
+    spec = get_kernel(kind)
+
+    def row_tile(rows):
+        x_r, a_r = rows
+        u_r = x_r / lengthscales
+
+        def col_step(acc, cols):
+            x_c, b_c = cols
+            u_c = x_c / lengthscales
+            r2 = scaled_sqdist(x_r, x_c, lengthscales)
+            # kappa' as reverse mode sees it through the clamps: zero where
+            # the distance was clamped to 0 or below the profile's floor.
+            dk = jnp.where(r2 > _R2_FLOOR, spec.dkappa_dr2(r2), 0.0)
+            c = jnp.matmul(a_r, b_c.T, precision=MVM_PRECISION)
+            g = dk * c
+            gu = jnp.matmul(g, u_c, precision=MVM_PRECISION)  # (bm, d)
+            t_tile = (
+                jnp.sum(u_r * u_r * jnp.sum(g, axis=1)[:, None], axis=0)
+                + jnp.sum(u_c * u_c * jnp.sum(g, axis=0)[:, None], axis=0)
+                - 2.0 * jnp.sum(u_r * gu, axis=0)
+            )
+            s_tile = jnp.sum(spec.kappa_from_r2(r2) * c)
+            return (acc[0] + s_tile, acc[1] + t_tile), None
+
+        acc0 = (jnp.zeros((), x.dtype), jnp.zeros((d,), x.dtype))
+        acc, _ = jax.lax.scan(col_step, acc0, (xc, bc))
+        return acc
+
+    s_rows, t_rows = jax.lax.map(row_tile, (xr, ar))
+    return jnp.sum(s_rows), jnp.sum(t_rows, axis=0)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(5, 6, 7))
+def _kernel_quadratic(lengthscales, signal, x, aw, b, kind, bm, bn):
+    """sum_t aw_t^T K(x, x; lengthscales, signal) b_t."""
+    return _kernel_quadratic_fwd(lengthscales, signal, x, aw, b, kind,
+                                 bm, bn)[0]
+
+
+def _kernel_quadratic_fwd(lengthscales, signal, x, aw, b, kind, bm, bn):
+    s_unit, t = _tile_sweep(lengthscales, x, aw, b, kind, bm, bn)
+    sig2 = signal**2
+    d_lengthscales = -2.0 * sig2 * t / lengthscales
+    d_signal = 2.0 * signal * s_unit
+    return sig2 * s_unit, (d_lengthscales, d_signal)
+
+
+def _kernel_quadratic_bwd(kind, bm, bn, res, g):
+    d_lengthscales, d_signal = res
+    return g * d_lengthscales, g * d_signal, None, None, None
+
+
+_kernel_quadratic.defvjp(_kernel_quadratic_fwd, _kernel_quadratic_bwd)
 
 
 def _weighted_quadratic(
@@ -49,9 +130,10 @@ def _weighted_quadratic(
     bn: int,
 ) -> jax.Array:
     """S(theta) = sum_t weights_t * a[:, t]^T H(theta) b[:, t]."""
-    kb = kernel_mvm_tiled(x, x, b, params, kind=kind, bm=bm, bn=bn)
-    hb = kb + (params.noise**2) * b
-    return jnp.sum(weights * jnp.sum(a * hb, axis=0))
+    aw = a * weights
+    kab = _kernel_quadratic(params.lengthscales, params.signal, x, aw, b,
+                            resolve_kind(kind, params), bm, bn)
+    return kab + (params.noise**2) * jnp.sum(aw * b)
 
 
 def mll_grad_estimate(

@@ -68,6 +68,21 @@ def test_softplus_roundtrip(theta):
     assert abs(back - theta) / theta < 1e-4
 
 
+def test_softplus_and_its_derivative_to_float32_rounding():
+    """No ``log`` inside: the TPU's is off by 1e-4, so the value is held to
+    float64 here, everywhere it does not underflow."""
+    x = np.linspace(-60.0, 60.0, 240_001, dtype=np.float32)
+    exact = np.logaddexp(0.0, np.float64(x))
+    sigmoid = 1.0 / (1.0 + np.exp(-np.float64(x)))
+    value = np.float64(jax.jit(softplus)(x))
+    slope = np.float64(jax.jit(jax.vmap(jax.grad(softplus)))(x))
+    for got, want in ((value, exact), (slope, sigmoid)):
+        normal = want > np.finfo(np.float32).tiny
+        ulps = np.abs(got - want)[normal] / np.spacing(
+            np.float32(want[normal]))
+        assert ulps.max() < 8.0, ulps.max()
+
+
 @_settings
 @given(sizes, dims, st.integers(0, 2**31 - 1))
 def test_scaled_sqdist_nonneg_and_zero_diag(n, d, seed):

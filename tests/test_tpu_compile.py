@@ -28,6 +28,7 @@ TILE = 1024
 N = 12 * TILE  # the pol train split (12,150 rows) padded to whole tiles
 S = 65  # 1 + 64 probes
 POL_TRAIN = (12_150, 26)
+GIB = 1024**3
 
 
 @pytest.fixture(scope="module")
@@ -87,6 +88,33 @@ def test_pol_outer_scan_fits_v5e_hbm(one_chip):
     compiled = outer_scan.lower(state, x, _sds((n,), one_chip),
                                 cfg=cfg, num_steps=2).compile()
     mem = compiled.memory_analysis()
+    used = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
+            + mem.output_size_in_bytes)
+    assert used < V5E_HBM_BYTES, mem
+
+
+@pytest.mark.parametrize("n, temp_limit", [
+    (20 * TILE, GIB),  # the 3droad cell's rows
+    (64 * TILE, V5E_HBM_BYTES),  # 3.2 times as many
+])
+def test_3droad_outer_scan_memory_on_v5e(one_chip, n, temp_limit):
+    """Eight budgeted steps at d=3 with the rank-100 preconditioner: the
+    gradient recomputes each kernel tile, so nothing O(n^2) is stored."""
+    cfg = OuterConfig(estimator="pathwise", warm_start=True, num_probes=S - 1,
+                      num_rff_pairs=1000,
+                      solver=SolverConfig(name="cg", max_epochs=10.0,
+                                          precond_rank=100),
+                      num_steps=8, bm=TILE, bn=TILE)
+    d = 3
+    state = jax.eval_shape(
+        lambda k: init_outer_state(k, cfg, jnp.zeros((n, d))),
+        jax.random.PRNGKey(0))
+    state = jax.tree.map(lambda a: _sds(a.shape, one_chip, a.dtype), state)
+    compiled = outer_scan.lower(state, _sds((n, d), one_chip),
+                                _sds((n,), one_chip), cfg=cfg,
+                                num_steps=8).compile()
+    mem = compiled.memory_analysis()
+    assert mem.temp_size_in_bytes < temp_limit, mem
     used = (mem.temp_size_in_bytes + mem.argument_size_in_bytes
             + mem.output_size_in_bytes)
     assert used < V5E_HBM_BYTES, mem
