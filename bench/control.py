@@ -5,7 +5,8 @@
         --seeds 1-12 --control-seeds 101-103 [--faults unchanged,altered \\
         --fault-seeds 201-203]
 
-For every seed the cell's traffic runs at the cell's own size for
+For every seed the cell's traffic runs, through the cell's own generator
+(``bench.generators``), at the cell's own size for
 ``--seconds`` (the run's length, so that as many fits are compared as a run
 compares) and the check's numbers of every fit are printed, one JSON line
 per seed with the worst of each. Set-up is repeated per seed; after the
@@ -38,8 +39,8 @@ def seeds(text: str) -> list[int]:
 
 def readings(cell, seed_list, seconds: float, mode: str) -> list[dict]:
     from bench import check
-    from bench.generators import closed_loop_fits as gen
 
+    gen = cell.generator
     rows = []
     for seed in seed_list:
         prep = gen.setup(cell, seed)

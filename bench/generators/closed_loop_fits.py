@@ -158,6 +158,25 @@ def program_temp_bytes(prep: Prepared) -> int:
                for k in chunk_lengths(prep.cfg.num_steps))
 
 
+def window_programs(cell) -> list:
+    """``outer_scan`` at each chunk length a fit of the cell runs, lowered
+    for the cell's shapes as the window runs it."""
+    import jax.numpy as jnp
+
+    from repro.core import init_outer_state, outer_scan
+
+    cfg = outer_config(cell.config, cell.traffic)
+    params = _init_params(cell.config)
+    n, d = int(cell.config["n_train"]), int(cell.config["d"])
+    x = jax.ShapeDtypeStruct((n, d), jnp.float32)
+    y = jax.ShapeDtypeStruct((n,), jnp.float32)
+    state = jax.eval_shape(
+        lambda key: init_outer_state(key, cfg, x, init_params=params),
+        jax.ShapeDtypeStruct((2,), jnp.uint32))
+    return [outer_scan.lower(state, x, y, cfg, k)
+            for k in sorted(chunk_lengths(cfg.num_steps))]
+
+
 def _output(res) -> check.FitOutput:
     probes, adam = res.state.probes, res.state.adam
 

@@ -26,7 +26,6 @@ import time
 T0 = time.perf_counter()
 
 import argparse  # noqa: E402
-import importlib  # noqa: E402
 import json  # noqa: E402
 import os  # noqa: E402
 import sys  # noqa: E402
@@ -138,8 +137,7 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         dev = jax.devices()[0]
         device = {"platform": dev.platform, "kind": dev.device_kind,
                   "count": len(jax.devices())}
-    gen = importlib.import_module(
-        f"bench.generators.{cell.traffic['generator']}")
+    gen = cell.generator
 
     prep = gen.setup(cell, seed)
     counter = CompileCounter()
@@ -148,13 +146,12 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
     session = start_trace() if trace else None
     win = gen.measure(prep, seconds)
     counter.on = False
-    summary = None
+    summary = profile = None
     if session is not None:
         t_trace = time.perf_counter()
         profile = session.stop_and_get_profile_data()
         t_read = time.perf_counter()
         summary = reduce(profile)
-        del profile
         log(f"[trace] collected in {t_read - t_trace:.1f} s, reduced in "
             f"{time.perf_counter() - t_read:.1f} s; the device trace misses "
             f"the window's last {summary.cut_s:.3f} s")
@@ -166,14 +163,17 @@ def run(workload: str, seed: int, seconds: float, trace: bool,
         device["memory_peak_bytes"] = memory_peak(
             used, lambda: gen.program_temp_bytes(prep))
 
+    # The profile stays in ctx for the per-layer readers, and goes after.
     ctx = {"cell": cell, "window": win, "setup_s": setup_s, "trace": summary,
-           "device_kind": device["kind"]}
+           "profile": profile, "device_kind": device["kind"]}
+    del profile
     metrics = {}
     entries = cell.per_layer if trace else cell.end_to_end
     for m in entries:
         value = cell.readers[m["name"]](ctx)
         if value is not None:
             metrics[m["name"]] = {"value": value, "unit": m["unit"]}
+    del ctx["profile"]
 
     t_check = time.perf_counter()
     compared = gen.compare(prep, win, cell.limits)

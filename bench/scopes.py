@@ -38,10 +38,9 @@ silently moved one.
 A program without the scopes, or a driver without the spans, gives
 ``None``: there is nothing to read.
 
-``bench/run.py`` frees the profile once its own reduction has run, before
-the per-layer readers run. So importing this module, which the phase
-readers do when the cell is loaded, makes each profiler session of the
-process keep the last profile it returns, for ``window_phases``.
+``window_phases`` reads the profile that ``bench/run.py`` keeps in
+``ctx["profile"]`` until the per-layer readers have run, and the programs
+from the cell's generator (``window_programs``, see ``bench.generators``).
 """
 from __future__ import annotations
 
@@ -324,65 +323,25 @@ def _fresh_compiles():
         compilation_cache.reset_cache()
 
 
-def outer_scan_texts(cell) -> list[str]:
-    """The compiled text of ``outer_scan`` at each chunk length a fit of
-    the cell runs, lowered for the cell's shapes as the window runs it and
-    compiled anew, so that it carries the scopes' metadata."""
-    import jax
-    import jax.numpy as jnp
-
-    from bench.generators import closed_loop_fits as gen
-    from repro.core import init_outer_state, outer_scan
-
-    cfg = gen.outer_config(cell.config, cell.traffic)
-    params = gen._init_params(cell.config)
-    n, d = int(cell.config["n_train"]), int(cell.config["d"])
-    x = jax.ShapeDtypeStruct((n, d), jnp.float32)
-    y = jax.ShapeDtypeStruct((n,), jnp.float32)
-    state = jax.eval_shape(
-        lambda key: init_outer_state(key, cfg, x, init_params=params),
-        jax.ShapeDtypeStruct((2,), jnp.uint32))
-    with _fresh_compiles():
-        return [outer_scan.lower(state, x, y, cfg, k).compile().as_text()
-                for k in sorted(gen.chunk_lengths(cfg.num_steps))]
-
-
-_KEPT: dict = {}
-_READ: dict = {}
-
-
-def _keep_profiles() -> None:
-    """Make each profiler session keep the last profile it returns."""
-    from jaxlib import _profiler
-
-    base = _profiler.ProfilerSession
-    if getattr(base, "keeps_profile", False):
-        return
-
-    class KeepingSession(base):
-        keeps_profile = True
-
-        def stop_and_get_profile_data(self):
-            _KEPT["profile"] = profile = super().stop_and_get_profile_data()
-            return profile
-
-    _profiler.ProfilerSession = KeepingSession
-
-
 def window_phases(ctx) -> Phases | None:
-    """The phases of the run's traced window, read once for all readers;
-    the kept profile is dropped after."""
-    key = id(ctx["window"])
-    if key in _READ:
-        return _READ[key]
-    profile = _KEPT.pop("profile", None)
-    if ctx["trace"] is None or profile is None:
+    """The phases of the run's traced window (``ctx["profile"]``), against
+    the programs of the cell's generator; read once for all readers and
+    kept in ``ctx["phases"]``."""
+    if "phases" not in ctx:
+        ctx["phases"] = _read_phases(ctx.get("profile"), ctx["cell"])
+    return ctx["phases"]
+
+
+def _read_phases(profile, cell) -> Phases | None:
+    if profile is None:
         return None
     t0 = time.perf_counter()
     _, spans = _host_spans(profile)
     result = None
     if any(span[2] == CHUNK_SPAN for span in spans):
-        texts = outer_scan_texts(ctx["cell"])
+        with _fresh_compiles():
+            texts = [lowered.compile().as_text()
+                     for lowered in cell.generator.window_programs(cell)]
         t1 = time.perf_counter()
         result = reduce_phases(profile, texts)
         print(f"[phases] programs compiled in {t1 - t0:.1f} s, reduced in "
@@ -398,8 +357,5 @@ def window_phases(ctx) -> Phases | None:
               + "; idle s by host span: "
               + ", ".join(f"{k}={v!r}" for k, v in result.idle_s.items()),
               file=sys.stderr)
-    _READ[key] = result
     return result
 
-
-_keep_profiles()

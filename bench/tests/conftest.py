@@ -6,7 +6,7 @@ They sit outside the repository's ``testpaths``. Everything but
 ``test_control.py`` runs on the CPU at tiny sizes; ``tiny_root`` builds a
 benchmark root whose one configuration is the ``pol`` configuration cut to
 n=256, d=3, 8 probes, 64 RFF pairs and 4 outer steps, under the real cells'
-traffic mixes, limits and metric readers.
+traffic mixes, generators, limits and metric readers.
 """
 import json
 import shutil
@@ -28,7 +28,7 @@ def make_root(path: Path, config_updates: dict | None = None) -> Path:
     ``tiny.fit_budget``, limited as ``pol.fit_tol`` and
     ``3droad.fit_budget``."""
     bench = path / "bench"
-    for sub in ("end_to_end", "layer_metrics", "traffic"):
+    for sub in ("end_to_end", "generators", "layer_metrics", "traffic"):
         shutil.copytree(BENCH / sub, bench / sub)
     (bench / "configs").mkdir()
     (bench / "limits").mkdir()

@@ -66,3 +66,22 @@ def test_the_dataset_seed_changes_the_data():
     assert not np.allclose(rows(x1, y1), rows(x2, y2))
     with pytest.raises(ValueError):
         seed_key(-1)
+
+
+def test_shares_of_peak_count_the_cells_chips():
+    from types import SimpleNamespace
+
+    from bench.spec import load_reader
+
+    read = load_reader("fit_mfu", "layer_metrics")
+    config = {"n_train": 20480, "d": 3, "num_probes": 64}
+    window = SimpleNamespace(seconds=41.5, histories=[
+        {"epochs": np.full(100, 10.0)}] * 4)
+
+    def fit_mfu(chips):
+        return read({"window": window, "device_kind": "TPU v5 lite",
+                     "cell": SimpleNamespace(config=config, chips=chips)})
+
+    assert fit_mfu(4) == pytest.approx(fit_mfu(1) / 4, rel=1e-12)
+    assert fit_mfu(1) == pytest.approx(
+        100 * 400 * flops.step_flops(20480, 3, 64, 10.0) / 41.5 / 197e12)

@@ -156,18 +156,25 @@ def test_phases_by_hand():
                                         "fit.chunk": 0.015})
 
 
-def test_phases_of_a_trace_recorded_on_the_chip():
+def _recorded():
     from jax.profiler import ProfileData
 
     profile = ProfileData.from_file(str(DATA / "small_scoped_trace.xplane.pb"))
-    text = (DATA / "small_scoped_trace.hlo.txt").read_text()
-    got = scopes.reduce_phases(profile, [text])
+    return profile, (DATA / "small_scoped_trace.hlo.txt").read_text()
+
+
+def _assert_recorded_phases(got):
     assert got.chunks == 2 and got.steps == 2
     want = {"solve_mvm": 46497, "precond": 1584, "solve": 46,
             "targets": 7904, "grad": 15920, "adam": 0, None: 8030}
     assert got.seconds == pytest.approx({k: v * 1e-9
                                          for k, v in want.items()})
     assert got.busy_s == pytest.approx(79981e-9)
+
+
+def test_phases_of_a_trace_recorded_on_the_chip():
+    profile, text = _recorded()
+    _assert_recorded_phases(scopes.reduce_phases(profile, [text]))
 
 
 @pytest.mark.parametrize("chunk_span,programs", [
@@ -178,15 +185,43 @@ def test_nothing_to_read_without_spans_or_scopes(chunk_span, programs):
     assert scopes.reduce_phases(_profile(chunk_span), programs) is None
 
 
-def test_session_keeps_its_profile_for_the_readers():
-    import jax.profiler  # noqa: F401 (registers the profile's type)
-    from jaxlib import _profiler
+class _Compiled:
+    """Stands for a lowered program: compiling it gives the recorded text."""
 
-    options = _profiler.ProfileOptions()
-    options.python_tracer_level = 0
-    profile = _profiler.ProfilerSession(options).stop_and_get_profile_data()
-    assert scopes._KEPT["profile"] is profile
-    ctx = {"window": object(), "trace": object(), "cell": None}
-    # A CPU profile has no TPU plane and no fit.chunk span.
-    assert scopes.window_phases(ctx) is None
-    assert "profile" not in scopes._KEPT
+    def __init__(self, text):
+        self.text = text
+
+    def compile(self):
+        return self
+
+    def as_text(self):
+        return self.text
+
+
+def test_window_phases_reads_the_profile_kept_in_ctx():
+    from types import SimpleNamespace
+
+    profile, text = _recorded()
+    stub = SimpleNamespace(window_programs=lambda cell: [_Compiled(text)])
+    ctx = {"cell": SimpleNamespace(generator=stub), "profile": profile}
+    got = scopes.window_phases(ctx)
+    _assert_recorded_phases(got)
+    assert scopes.window_phases(ctx) is got  # read once for all readers
+    # An untraced run keeps no profile: nothing to read.
+    assert scopes.window_phases({"cell": ctx["cell"], "profile": None}) is None
+
+
+def test_the_generators_programs_carry_the_scopes(tiny_root):
+    from bench.spec import load_cell
+
+    cell = load_cell("tiny.fit_budget", root=tiny_root)
+    with scopes._fresh_compiles():
+        texts = [p.compile().as_text()
+                 for p in cell.generator.window_programs(cell)]
+    progs = [scopes.Program.parse(t) for t in texts]
+    assert len(progs) == len(cell.generator.chunk_lengths(
+        cell.config["outer_steps"]))
+    assert all(p.module == "jit_outer_scan" for p in progs)
+    for prog in progs:
+        phases = {scopes.phase_of(n) for n in prog.op_names.values()}
+        assert {"solve_mvm", "precond", "grad"} <= phases

@@ -2,8 +2,11 @@
 import json
 import re
 
+import pytest
+
 from conftest import BENCH, ROOT, make_root
 
+from bench.generators import CONTRACT
 from bench.spec import load_cell
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
@@ -54,8 +57,22 @@ def test_configs_files_and_cells():
         assert config["reduced"] == c["reduced"]
     pairs = [(w["config"], w["traffic"]) for w in b["workloads"]]
     assert len(pairs) == len(set(pairs))
-    assert all(w["chips"] == 1 and len(w["why"]) <= 200
-               for w in b["workloads"])
+    assert cell_rule_breaches(b) == []
+
+
+def cell_rule_breaches(b) -> list[str]:
+    """The contract's rules on the cells: 1 or 4 chips, at most half of the
+    cells (rounded down, and one always) on four, a ``why`` of at most 200
+    characters on one line."""
+    cells = b["workloads"]
+    out = [f"{w['name']}: chips {w['chips']}" for w in cells
+           if w["chips"] not in (1, 4)]
+    out += [f"{w['name']}: why" for w in cells
+            if not 1 <= len(w["why"]) <= 200 or "\n" in w["why"]]
+    four = sum(w["chips"] == 4 for w in cells)
+    if four > max(1, len(cells) // 2):
+        out.append(f"{four} of {len(cells)} cells on four chips")
+    return out
 
 
 def test_every_cell_resolves_with_its_metrics():
@@ -67,7 +84,9 @@ def test_every_cell_resolves_with_its_metrics():
         assert set(cell.readers) == e2e | {m["name"] for m in cell.per_layer}
         assert set(cell.limits) == {"solve_gap", "probe_gap", "grad_gap",
                                    "adam_gap", "step1_gap"}
-        assert cell.traffic["generator"] == "closed_loop_fits"
+        assert (BENCH / "generators"
+                / f"{cell.traffic['generator']}.py").exists()
+        assert all(callable(getattr(cell.generator, f)) for f in CONTRACT)
 
 
 def test_budget_metric_only_where_listed():
@@ -105,3 +124,51 @@ def test_a_new_config_traffic_and_metric_need_only_new_files(tmp_path):
     assert cell.traffic["max_epochs"] == 3
     assert cell.readers["dummy_metric"]({}) == 42.0
     assert "dummy_metric" not in load_cell("tiny.fit_tol", root=root).readers
+
+
+def test_a_four_chip_cell_with_its_own_generator_needs_only_new_files(
+        tmp_path):
+    """A cell on four chips, whose traffic names a generator of its own, is
+    added by new files and a new BENCHMARK.json entry alone."""
+    root = make_root(tmp_path)
+    bench = root / "bench"
+    stubs = "\n\n".join(f"def {f}(*args):\n    return {f!r}\n"
+                        for f in CONTRACT)
+    (bench / "generators" / "dummy_rows.py").write_text(stubs)
+    traffic = json.loads((BENCH / "traffic" / "fit_budget.json").read_text())
+    traffic["generator"] = "dummy_rows"
+    (bench / "traffic" / "dummy_rows4.json").write_text(json.dumps(traffic))
+    (bench / "limits" / "tiny.dummy_rows4.json").write_text(
+        (BENCH / "limits" / "3droad.fit_budget.json").read_text())
+    b = json.loads((root / "BENCHMARK.json").read_text())
+    b["workloads"].append({"name": "tiny.dummy_rows4", "config": "tiny",
+                           "traffic": "dummy_rows4", "chips": 4,
+                           "why": "test: rows sharded over four chips"})
+    (root / "BENCHMARK.json").write_text(json.dumps(b))
+    cell = load_cell("tiny.dummy_rows4", root=root)
+    assert cell.chips == 4
+    assert cell.generator.window_programs(cell) == "window_programs"
+    assert cell.generator.__file__ == str(bench / "generators"
+                                          / "dummy_rows.py")
+    assert cell_rule_breaches(b) == []
+    # A generator without every function of the contract is refused.
+    (bench / "generators" / "dummy_rows.py").write_text(
+        stubs.replace("def window_programs", "def other"))
+    with pytest.raises(AttributeError, match="window_programs"):
+        load_cell("tiny.dummy_rows4", root=root)
+
+
+@pytest.mark.parametrize("chips,breaches", [
+    ([1, 1, 4], 0),
+    ([1, 4, 4], 1),  # a second four-chip cell among three
+    ([1, 1, 4, 4], 0),
+    ([4], 0),  # one always may
+    ([4, 4], 1),
+    ([1, 2], 1),
+])
+def test_cell_rules(chips, breaches):
+    b = {"workloads": [{"name": f"c{i}", "chips": c, "why": "w"}
+                       for i, c in enumerate(chips)]}
+    assert len(cell_rule_breaches(b)) == breaches
+    b["workloads"][0]["why"] = "w" * 201
+    assert len(cell_rule_breaches(b)) == breaches + 1
